@@ -62,6 +62,9 @@ def main():
             """)
             env = dict(os.environ)
             env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+            # the 512-device host mesh lives on the CPU; never reach for an
+            # accelerator the parent may hold
+            env["JAX_PLATFORMS"] = "cpu"
             r = subprocess.run([sys.executable, "-c", code], env=env,
                                capture_output=True, text=True, timeout=590)
             if r.returncode != 0:

@@ -38,6 +38,9 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="tiny configs, relaxed assertions (CI smoke job)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.smoke:
         os.environ["BLITZ_SMOKE"] = "1"  # read by benchmarks.common.smoke()
 

@@ -35,8 +35,17 @@ def _modname(arch: str) -> str:
     return arch.replace("-", "_").replace(".", "_")
 
 
-def get_config(name: str, *, reduced: bool = False) -> ModelConfig:
+def get_config(name: str, *, reduced: bool = False, one_chip: bool = False) -> ModelConfig:
+    """``reduced``: the tiny CPU-test variant.  ``one_chip``: published
+    widths with depth cut to what one 16 GiB chip holds (``ONE_CHIP`` in the
+    arch's module; only archs that define one)."""
+    if reduced and one_chip:
+        raise ValueError("reduced and one_chip are exclusive variants")
     mod = importlib.import_module(f"repro.configs.{_modname(name)}")
+    if one_chip:
+        if not hasattr(mod, "ONE_CHIP"):
+            raise ValueError(f"{name} has no one-chip variant")
+        return mod.ONE_CHIP
     return mod.REDUCED if reduced else mod.CONFIG
 
 

@@ -21,12 +21,9 @@ Both are validated numerically on 8 host devices in
 from __future__ import annotations
 
 import functools
-from typing import Any
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -66,8 +63,10 @@ def chain_broadcast_blocks(
         b = s - chain_pos
         valid = (b >= 0) & (b < n_blocks)
         bc = jnp.clip(b, 0, n_blocks - 1)
-        stored = jax.lax.dynamic_update_index_in_dim(out, cur, bc, 0)
-        out = jnp.where(valid, stored, out)
+        old = jax.lax.dynamic_index_in_dim(out, bc, 0, keepdims=False)
+        out = jax.lax.dynamic_update_index_in_dim(
+            out, jnp.where(valid, cur, old), bc, 0
+        )
         # forward to successor
         nxt = jax.lax.ppermute(cur, axis_name, perm)
         return (nxt, out), None
@@ -79,35 +78,52 @@ def chain_broadcast_blocks(
 
 
 def chain_broadcast(
-    params_flat: jax.Array,  # (total_elems,) valid on rank 0 of `axis_name`
+    src: jax.Array,  # (total_elems,) on the device of chain rank 0 only
     mesh: Mesh,
     axis_name: str,
     n_blocks: int = 16,
 ) -> jax.Array:
-    """Jit-compiled wrapper: broadcast a flat parameter vector from chain
-    rank 0 to every rank along `axis_name` (other mesh axes untouched)."""
+    """Broadcast a flat parameter vector from chain rank 0 to every rank of
+    the 1-D ``mesh`` along ``axis_name``.
+
+    The bytes start on rank 0 alone: the other ranks get empty receive
+    buffers allocated on their own device, so every byte they end with was
+    moved by the chain's ppermutes.  Returns a ``(n_ranks, total)`` array
+    sharded ``P(axis_name)``: row ``r`` is rank ``r``'s own copy."""
+    devices = list(mesh.devices.flat)
     n_ranks = mesh.shape[axis_name]
-    total = params_flat.shape[0]
+    if len(devices) != n_ranks:
+        raise ValueError("chain_broadcast takes a 1-D mesh over the chain axis")
+    total = src.shape[0]
+    sharding = NamedSharding(mesh, P(axis_name))
+    rows = [jax.device_put(src, devices[0])[None]] + [
+        jnp.zeros((1, total), src.dtype, device=d) for d in devices[1:]
+    ]
+    stacked = jax.make_array_from_single_device_arrays((n_ranks, total), sharding, rows)
+    return _chain_broadcast_fn(mesh, axis_name, n_blocks, total)(stacked)
+
+
+@functools.lru_cache(maxsize=8)
+def _chain_broadcast_fn(mesh: Mesh, axis_name: str, n_blocks: int, total: int):
+    """The jitted chain program, built once per (mesh, size) so a repeated
+    broadcast reuses its executable."""
+    n_ranks = mesh.shape[axis_name]
     pad = (-total) % n_blocks
     padded = total + pad
 
-    other_axes = tuple(a for a in mesh.axis_names if a != axis_name)
-    in_spec = P()  # replicated view in; per-rank copies inside
-    out_spec = P()
-
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
-        in_specs=(in_spec,),
-        out_specs=out_spec,
-        check_rep=False,
+        in_specs=(P(axis_name),),
+        out_specs=P(axis_name),
+        check_vma=False,
     )
-    def _bcast(flat):
-        blocks = jnp.pad(flat, (0, pad)).reshape(n_blocks, padded // n_blocks)
+    def _bcast(row):
+        blocks = jnp.pad(row[0], (0, pad)).reshape(n_blocks, padded // n_blocks)
         out = chain_broadcast_blocks(blocks, axis_name, n_ranks)
-        return out.reshape(padded)[:total]
+        return out.reshape(1, padded)[:, :total]
 
-    return jax.jit(_bcast)(params_flat)
+    return jax.jit(_bcast)
 
 
 # ---------------------------------------------------------------------------

@@ -266,11 +266,18 @@ def _is_spec(x) -> bool:
     return isinstance(x, TensorSpec)
 
 
+# one compiled program per distinct TensorSpec: XLA fuses the float32
+# draw, the scale and the cast, so only the leaf in its own dtype is ever
+# allocated on the device (an eager draw of a stacked (L, d, d_ff) leaf
+# would hold two float32 temporaries of twice the leaf's size)
+_init_leaf_jit = jax.jit(_init_leaf, static_argnums=1)
+
+
 def init_from_template(key: jax.Array, template: Any) -> Any:
     """Materialize parameter arrays from a TensorSpec pytree."""
     leaves, treedef = jax.tree.flatten(template, is_leaf=_is_spec)
     keys = jax.random.split(key, max(len(leaves), 1))
-    arrays = [_init_leaf(k, s) for k, s in zip(keys, leaves)]
+    arrays = [_init_leaf_jit(k, s) for k, s in zip(keys, leaves)]
     return jax.tree.unflatten(treedef, arrays)
 
 

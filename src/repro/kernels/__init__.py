@@ -6,7 +6,7 @@ kernels for LLM come from FlashInfer"); the TPU-native equivalents live here:
   flash_attention.py  — blocked causal GQA prefill attention
   decode_attention.py — flash-decoding single-token GQA over the KV cache
   rmsnorm.py          — fused RMSNorm
-  ops.py              — jit'd dispatch (pallas on TPU / oracle on CPU)
+  ops.py              — jit'd wrappers (impl='pallas' | 'ref'; no fallback)
   ref.py              — pure-jnp oracles (shared with the model code)
 """
 

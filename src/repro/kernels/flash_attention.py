@@ -2,6 +2,12 @@
 
 TPU adaptation notes (vs. the CUDA flash-attention the serving papers use):
 
+  * The kernel works on a head-major ``(B, H, S, D)`` view, so every block
+    is ``(1, 1, block, D)``: the last two block dimensions are
+    ``(block_q|block_k, head_dim)``, which the TPU compiler tiles as
+    (sublanes, lanes).  A head axis among the last two would need blocks
+    of 8 heads, so the wrapper transposes the public ``(B, S, H, D)``
+    layout instead.
   * The grid is ``(batch, q_heads, q_blocks, kv_blocks)``; TPU executes the
     grid *sequentially* with the last dimension fastest, so the kv axis is an
     accumulation axis: running (max, denom, acc) live in VMEM scratch and the
@@ -37,12 +43,12 @@ NEG_INF = -1e30
 
 
 def _flash_kernel(
-    q_ref,  # (1, block_q, 1, D)
-    k_ref,  # (1, block_k, 1, D)
-    v_ref,  # (1, block_k, 1, D)
-    o_ref,  # (1, block_q, 1, D)
-    m_ref,  # scratch (block_q,)   f32
-    l_ref,  # scratch (block_q,)   f32
+    q_ref,  # (1, 1, block_q, D)
+    k_ref,  # (1, 1, block_k, D)
+    v_ref,  # (1, 1, block_k, D)
+    o_ref,  # (1, 1, block_q, D)
+    m_ref,  # scratch (block_q, 1) f32
+    l_ref,  # scratch (block_q, 1) f32
     acc_ref,  # scratch (block_q, D) f32
     *,
     scale: float,
@@ -66,9 +72,9 @@ def _flash_kernel(
     k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
 
     def _step():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)  # (bq, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bk, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32)  # (bq, D)
+        k = k_ref[0, 0].astype(jnp.float32)  # (bk, D)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # (bq, bk)
@@ -78,11 +84,11 @@ def _flash_kernel(
         s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
         m_ref[...] = m_new
@@ -95,8 +101,8 @@ def _flash_kernel(
 
     @pl.when(ki == nk - 1)
     def _emit():
-        denom = jnp.maximum(l_ref[...], 1e-30)[:, None]
-        o_ref[0, :, 0, :] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        denom = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 def flash_attention(
@@ -108,7 +114,7 @@ def flash_attention(
     softmax_scale: float | None = None,
     block_q: int = 128,
     block_k: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     b, sq, h, d = q.shape
     _, sk, kv, _ = k.shape
@@ -119,11 +125,10 @@ def flash_attention(
     block_k = min(block_k, max(sk, 8))
     sq_pad = -(-sq // block_q) * block_q
     sk_pad = -(-sk // block_k) * block_k
-    if sq_pad != sq:
-        q = jnp.pad(q, ((0, 0), (0, sq_pad - sq), (0, 0), (0, 0)))
-    if sk_pad != sk:
-        k = jnp.pad(k, ((0, 0), (0, sk_pad - sk), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, sk_pad - sk), (0, 0), (0, 0)))
+    # head-major views: (B, H, S, D) / (B, KV, S, D)
+    q = jnp.pad(q, ((0, 0), (0, sq_pad - sq), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    k = jnp.pad(k, ((0, 0), (0, sk_pad - sk), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
+    v = jnp.pad(v, ((0, 0), (0, sk_pad - sk), (0, 0), (0, 0))).transpose(0, 2, 1, 3)
 
     grid = (b, h, sq_pad // block_q, sk_pad // block_k)
 
@@ -139,17 +144,17 @@ def flash_attention(
         ),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, d), lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda bi, hi, qi, ki: (bi, ki, hi // n_rep, 0)),
-            pl.BlockSpec((1, block_k, 1, d), lambda bi, hi, qi, ki: (bi, ki, hi // n_rep, 0)),
+            pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, qi, ki: (bi, hi // n_rep, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, d), lambda bi, hi, qi, ki: (bi, hi // n_rep, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, d), lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sq_pad, h, d), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, sq_pad, d), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),  # running max
-            pltpu.VMEM((block_q,), jnp.float32),  # running denom
+            pltpu.VMEM((block_q, 1), jnp.float32),  # running max
+            pltpu.VMEM((block_q, 1), jnp.float32),  # running denom
             pltpu.VMEM((block_q, d), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
     )(q, k, v)
-    return out[:, :sq]
+    return out[:, :, :sq].transpose(0, 2, 1, 3)

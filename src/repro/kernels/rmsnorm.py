@@ -33,7 +33,7 @@ def fused_rmsnorm(
     eps: float = 1e-5,
     *,
     block_n: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     orig_shape = x.shape
     d = x.shape[-1]

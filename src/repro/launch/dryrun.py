@@ -1,13 +1,15 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run (deliverable e): lower + compile every assigned
 (architecture x input-shape) cell on the production meshes and extract the
 roofline terms from the compiled artifact.
 
-The two lines above MUST stay first: jax locks the device count at first
+The three lines above MUST stay first: jax locks the device count at first
 initialization, and the 512 placeholder host devices exist only for this
-script — smoke tests and benchmarks see 1 device.
+script — smoke tests and benchmarks see 1 device.  The process is pinned to
+the CPU so that it never takes an accelerator that another process holds.
 
 Per cell this script records (one JSON per cell under --out):
   * per-device parameter/cache/argument/temp bytes (memory_analysis → proves

@@ -21,17 +21,12 @@ from __future__ import annotations
 import jax
 
 
-def _auto(n: int):
-    return (jax.sharding.AxisType.Auto,) * n
-
-
 def make_mesh_compat(shape, axes) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with explicit-Auto axis types where the installed
-    jax supports them (``AxisType`` landed after 0.4.x; older versions only
-    have auto axes, so omitting the kwarg is equivalent)."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(shape, axes, axis_types=_auto(len(shape)))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis typed Auto (sharding propagated by
+    the compiler), which the model's logical-axis rules assume."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(shape)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

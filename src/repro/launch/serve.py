@@ -25,6 +25,13 @@ via multicast when requests arrive:
   PYTHONPATH=src python -m repro.launch.serve --maas \
       --models granite-8b,qwen1.5-4b,minicpm3-4b --requests 24
 
+Compute runs the arch's reduced config by default (CPU tests).  With
+``--one-chip`` it runs the arch's one-chip variant instead: published
+widths with depth cut to one chip's share (``ONE_CHIP`` in
+``repro/configs/<arch>.py``), for the colocated and the ``--disagg`` path:
+
+  python -m repro.launch.serve --arch granite-8b --one-chip --prompt-len 512
+
 This is the runnable counterpart of the cluster-scale *simulator*
 (repro.core.simulator), which reproduces the paper's figures; here every
 forward pass is a real jitted model execution.
@@ -43,23 +50,29 @@ from repro.core import multicast as mc
 from repro.core import topology as topo_mod
 from repro.core.live_scaling import LiveSession
 from repro.core.parameter_pool import ParameterPool
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as TF
 from repro.serving.engine import InstanceEngine, ServeRequest
 from repro.serving.router import Router
 
 
-def run_disagg(args) -> None:
+def load_model(args):
+    """The compute config (reduced, or ``--one-chip``) and its parameters,
+    drawn from ``--seed``."""
+    cfg = get_config(args.arch, reduced=not args.one_chip, one_chip=args.one_chip)
+    return cfg, TF.init_params(jax.random.PRNGKey(args.seed), cfg)
+
+
+def run_disagg(args, cfg, params):
     """PD-disaggregated serving: prefill pool → KV migration → decode pool,
-    autoscaled with decode pre-scaling + prefill→decode mutation (§5.4)."""
+    autoscaled with decode pre-scaling + prefill→decode mutation (§5.4).
+    Returns the runtime; exits non-zero when a request was dropped."""
     from repro.core.autoscaler import PolicyConfig
     from repro.serving.disagg import ClusterRuntime
 
-    cfg = get_config(args.arch, reduced=True)
     # network model (live-scale + KV-migration volumes) uses the FULL
-    # architecture footprint; compute runs the reduced config
+    # architecture footprint; compute runs the reduced or one-chip config
     model_bytes = get_config(args.arch).approx_params() * 2
-    key = jax.random.PRNGKey(args.seed)
-    params = TF.init_params(key, cfg)
     rng = np.random.default_rng(args.seed)
     max_seq = args.prompt_len + args.gen_len + 8
 
@@ -112,6 +125,7 @@ def run_disagg(args) -> None:
     )
     if not completed_all or dropped != 0:
         raise SystemExit(f"FAIL: {dropped} request(s) dropped or token-gapped")
+    return rt
 
 
 def run_maas(args) -> None:
@@ -200,35 +214,11 @@ def run_maas(args) -> None:
         raise SystemExit(f"FAIL: {dropped} request(s) dropped or token-gapped")
 
 
-def main() -> None:
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="granite-8b")
-    ap.add_argument("--requests", type=int, default=24)
-    ap.add_argument("--prompt-len", type=int, default=32)
-    ap.add_argument("--gen-len", type=int, default=16)
-    ap.add_argument("--n-slots", type=int, default=4)
-    ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--disagg", action="store_true",
-                    help="run the PD-disaggregated runtime (prefill/decode pools)")
-    ap.add_argument("--n-prefill", type=int, default=2)
-    ap.add_argument("--n-decode", type=int, default=1)
-    ap.add_argument("--maas", action="store_true",
-                    help="serve several models on one fleet (MaaS control plane)")
-    ap.add_argument("--models", default="granite-8b,qwen1.5-4b,minicpm3-4b",
-                    help="comma-separated arch ids for --maas")
-    args = ap.parse_args()
-
-    if args.maas:
-        run_maas(args)
-        return
-    if args.disagg:
-        run_disagg(args)
-        return
-
-    cfg = get_config(args.arch, reduced=True)
-    key = jax.random.PRNGKey(args.seed)
-    params = TF.init_params(key, cfg)
+def run_colocated(args, cfg, params) -> list[ServeRequest]:
+    """One engine serves a burst while a second live-scales in beside it.
+    Returns the finished requests; exits non-zero when any went unserved."""
     rng = np.random.default_rng(args.seed)
+    max_seq = args.prompt_len + args.gen_len + 8
 
     # --- cluster state: topology + O(1) parameter pool --------------------
     topo = topo_mod.make_cluster(2, 4, bw_gbps=100.0)
@@ -240,16 +230,13 @@ def main() -> None:
     topo.device(0).role = topo_mod.Role.COLOCATED
 
     # --- engine 0 serves; burst arrives ------------------------------------
-    eng0 = InstanceEngine(cfg, params, n_slots=args.n_slots, max_seq=args.prompt_len + args.gen_len + 8)
+    eng0 = InstanceEngine(cfg, params, n_slots=args.n_slots, max_seq=max_seq)
     router = Router()
     t0 = time.perf_counter()
-    reqs = []
     for i in range(args.requests):
         prompt = rng.integers(0, cfg.vocab_size, size=args.prompt_len).astype(np.int32)
         rid = router.submit(args.prompt_len, args.gen_len, time.perf_counter() - t0)
-        r = ServeRequest(rid, prompt, args.gen_len)
-        reqs.append(r)
-        eng0.submit(r)
+        eng0.submit(ServeRequest(rid, prompt, args.gen_len))
 
     # --- load monitor trips -> plan a scale-out ----------------------------
     queue_depth = len(eng0.queue)
@@ -267,7 +254,7 @@ def main() -> None:
     )
 
     # --- live scaling: engine 1 starts with 0 layers, gains them over time -
-    eng1 = InstanceEngine(cfg, params, n_slots=args.n_slots, max_seq=args.prompt_len + args.gen_len + 8)
+    eng1 = InstanceEngine(cfg, params, n_slots=args.n_slots, max_seq=max_seq)
     eng1.set_loaded_layers(0)
     session = LiveSession(
         n_layers=cfg.n_layers,
@@ -276,9 +263,9 @@ def main() -> None:
         started_at=time.perf_counter(),
     )
 
-    done = 0
+    finished: list[ServeRequest] = []
     steps = 0
-    while done < args.requests and steps < 10_000:
+    while len(finished) < args.requests and steps < 10_000:
         steps += 1
         now = time.perf_counter()
         k = session.layers_loaded(now)
@@ -290,13 +277,14 @@ def main() -> None:
                 eng1.submit(eng0.queue.pop())
         for eng in (eng0, eng1) if eng1.can_serve_alone() else (eng0,):
             for r in eng.step():
-                done += 1
+                finished.append(r)
                 router.note_first_token(r.rid, now - t0)
                 router.note_done(r.rid)
         if steps % 20 == 0:
             print(
                 f"[live] step {steps} loaded {k}/{cfg.n_layers} layers "
-                f"boost x{mult:.2f} done {done}/{args.requests} phase={session.phase.value}"
+                f"boost x{mult:.2f} done {len(finished)}/{args.requests} "
+                f"phase={session.phase.value}"
             )
 
     rep = router.slo_report()
@@ -304,6 +292,49 @@ def main() -> None:
         f"served {rep.n} requests in {time.perf_counter()-t0:.2f}s  "
         f"mean_ttft {rep.mean_ttft*1e3:.0f}ms attainment {rep.attainment:.0%}"
     )
+    if len(finished) < args.requests:
+        raise SystemExit(
+            f"FAIL: {args.requests - len(finished)} request(s) unserved "
+            f"after {steps} steps"
+        )
+    return finished
+
+
+def build_parser() -> argparse.ArgumentParser:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--arch", default="granite-8b")
+    ap.add_argument("--one-chip", action="store_true",
+                    help="compute at published widths, depth cut to one chip's share "
+                         "(the arch's ONE_CHIP config) instead of the reduced config")
+    ap.add_argument("--requests", type=int, default=24)
+    ap.add_argument("--prompt-len", type=int, default=32)
+    ap.add_argument("--gen-len", type=int, default=16)
+    ap.add_argument("--n-slots", type=int, default=4)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--disagg", action="store_true",
+                    help="run the PD-disaggregated runtime (prefill/decode pools)")
+    ap.add_argument("--n-prefill", type=int, default=2)
+    ap.add_argument("--n-decode", type=int, default=1)
+    ap.add_argument("--maas", action="store_true",
+                    help="serve several models on one fleet (MaaS control plane)")
+    ap.add_argument("--models", default="granite-8b,qwen1.5-4b,minicpm3-4b",
+                    help="comma-separated arch ids for --maas")
+    return ap
+
+
+def main() -> None:
+    args = build_parser().parse_args()
+    enable_compile_cache()
+    if args.maas:
+        if args.one_chip:
+            raise SystemExit("--one-chip serves one model; it does not apply to --maas")
+        run_maas(args)
+        return
+    cfg, params = load_model(args)
+    if args.disagg:
+        run_disagg(args, cfg, params)
+    else:
+        run_colocated(args, cfg, params)
 
 
 if __name__ == "__main__":

@@ -26,17 +26,22 @@ def _run(code: str) -> str:
 
 def test_chain_broadcast_delivers_to_all_ranks():
     """The pipelined ppermute chain broadcast (Fig. 13a TPU adaptation):
-    every rank ends with the full parameter vector injected at rank 0."""
+    the vector starts on rank 0 only, and every rank's OWN copy ends equal
+    to it (not rank 0's view of it)."""
     _run("""
         import jax, jax.numpy as jnp, numpy as np
-        from jax.sharding import Mesh
         from repro.core.collectives import chain_broadcast
         from repro.launch.mesh import make_mesh_compat
 
         mesh = make_mesh_compat((8,), ("chain",))
-        params = jnp.arange(1000, dtype=jnp.float32)
-        out = chain_broadcast(params, mesh, "chain", n_blocks=4)
-        np.testing.assert_allclose(np.asarray(out), np.arange(1000))
+        src = jax.device_put(jnp.arange(1000, dtype=jnp.float32), jax.devices()[0])
+        out = chain_broadcast(src, mesh, "chain", n_blocks=4)
+        assert out.shape == (8, 1000)
+        shards = sorted(out.addressable_shards, key=lambda s: s.index[0].start)
+        assert [s.device for s in shards] == list(mesh.devices.flat)
+        for rank, shard in enumerate(shards):
+            assert shard.index[0] == slice(rank, rank + 1)
+            np.testing.assert_array_equal(np.asarray(shard.data)[0], np.arange(1000))
         print("ok")
     """)
 
@@ -63,7 +68,6 @@ def test_sharded_group_transfer_allgather():
     _run("""
         import functools
         import jax, jax.numpy as jnp, numpy as np
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.core.collectives import sharded_group_transfer
         from repro.launch.mesh import make_mesh_compat
@@ -71,9 +75,9 @@ def test_sharded_group_transfer_allgather():
         mesh = make_mesh_compat((2, 4), ("chain", "scaleup"))
         full = jnp.arange(64, dtype=jnp.float32)
 
-        @functools.partial(shard_map, mesh=mesh,
+        @functools.partial(jax.shard_map, mesh=mesh,
                            in_specs=(P(None, "scaleup"),), out_specs=P("chain", None),
-                           check_rep=False)
+                           check_vma=False)
         def xfer(shard):
             out = sharded_group_transfer(shard[0], "scaleup", "chain", 0, 1)
             return out[None]

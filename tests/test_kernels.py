@@ -89,12 +89,26 @@ def test_rmsnorm_matches_oracle(shape, dtype):
 
 
 def test_ops_dispatch_cpu_uses_ref():
+    """Off the TPU the wrappers run the oracle only when asked (impl='ref'),
+    the interpreter only when asked (interpret=True), and otherwise refuse
+    instead of falling back."""
     from repro.kernels import ops
 
     b, s, h, kv, d = 1, 16, 4, 2, 8
     q = jax.random.normal(KEY, (b, s, h, d))
     k = jax.random.normal(KEY, (b, s, kv, d))
     v = jax.random.normal(KEY, (b, s, kv, d))
-    out = ops.flash_attention(q, k, v, causal=True)
     want = ref.flash_attention_ref(q, k, v, causal=True)
+    out = ops.flash_attention(q, k, v, causal=True, impl="ref")
     np.testing.assert_allclose(out, want, atol=1e-5, rtol=1e-5)
+    out = ops.flash_attention(q, k, v, causal=True, block_q=8, block_k=8, interpret=True)
+    np.testing.assert_allclose(out, want, atol=1e-5, rtol=1e-5)
+    with pytest.raises(RuntimeError, match="interpret=True"):
+        ops.flash_attention(q, k, v, causal=True)
+    x = jax.random.normal(KEY, (3, 16))
+    w = jnp.ones((16,))
+    with pytest.raises(RuntimeError, match="interpret=True"):
+        ops.rmsnorm(x, w)
+    with pytest.raises(RuntimeError, match="interpret=True"):
+        ops.decode_attention(q[:, 0], k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
+                             jnp.array([s]))

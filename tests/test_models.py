@@ -1,5 +1,7 @@
 """Per-arch smoke tests (reduced configs) + model numerics properties."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -150,3 +152,39 @@ def test_forward_layers_range_composes():
         np.testing.assert_allclose(
             out.astype(jnp.float32), full.astype(jnp.float32), atol=1e-2, rtol=1e-2
         )
+
+
+@pytest.mark.parametrize("arch", ["granite-8b", "mamba2-370m"])
+def test_jitted_param_init_equals_eager_init(arch):
+    """Per-leaf jitted init (no float32 temporaries on the device) draws the
+    same values as the same init run eagerly op by op.  XLA fuses the
+    normal draw's last multiply with the init scale, which moves some float32
+    values by one ulp; after the cast to bf16 that shows at a rounding
+    boundary only, as at most one bf16 ulp in a handful of elements (float32
+    leaves such as Mamba2's A init likewise move by at most one ulp)."""
+    cfg = get_config(arch, reduced=True)
+    jitted = TF.init_params(KEY, cfg)
+    with jax.disable_jit():
+        eager = TF.init_params(KEY, cfg)
+    n_diff = n_total = 0
+    for a, b in zip(jax.tree.leaves(jitted), jax.tree.leaves(eager)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        uint = {2: np.uint16, 4: np.uint32}[a.dtype.itemsize]
+        bits_a = np.asarray(a).view(uint).astype(np.int64)
+        bits_b = np.asarray(b).view(uint).astype(np.int64)
+        assert np.abs(bits_a - bits_b).max() <= 1  # one ulp, same sign
+        n_diff += int((bits_a != bits_b).sum())
+        n_total += bits_a.size
+    assert n_diff <= n_total * 1e-3
+
+
+def test_granite_one_chip_config_keeps_published_widths():
+    """The one-chip variant cuts depth only: one stage of two."""
+    full = get_config("granite-8b")
+    one = get_config("granite-8b", one_chip=True)
+    assert one.n_layers == full.n_layers // 2 == 18
+    assert dataclasses.replace(one, n_layers=full.n_layers) == full
+    with pytest.raises(ValueError, match="one-chip"):
+        get_config("qwen1.5-4b", one_chip=True)
+    with pytest.raises(ValueError):
+        get_config("granite-8b", reduced=True, one_chip=True)
