@@ -264,6 +264,7 @@ def run_colocated(args, cfg, params) -> list[ServeRequest]:
     )
 
     finished: list[ServeRequest] = []
+    first_token_seen: set[int] = set()
     steps = 0
     while len(finished) < args.requests and steps < 10_000:
         steps += 1
@@ -276,9 +277,15 @@ def run_colocated(args, cfg, params) -> list[ServeRequest]:
             while len(eng0.queue) > len(eng1.queue):
                 eng1.submit(eng0.queue.pop())
         for eng in (eng0, eng1) if eng1.can_serve_alone() else (eng0,):
-            for r in eng.step():
+            done = eng.step()
+            delivered = time.perf_counter() - t0
+            # TTFT: stamped in the step that delivered the request's first token
+            for r in [*eng.active.values(), *done]:
+                if r.rid not in first_token_seen:
+                    first_token_seen.add(r.rid)
+                    router.note_first_token(r.rid, delivered)
+            for r in done:
                 finished.append(r)
-                router.note_first_token(r.rid, now - t0)
                 router.note_done(r.rid)
         if steps % 20 == 0:
             print(
@@ -288,9 +295,14 @@ def run_colocated(args, cfg, params) -> list[ServeRequest]:
             )
 
     rep = router.slo_report()
+    es = [eng0.stats, eng1.stats]
+    syncs, tokens = sum(e.host_syncs for e in es), sum(e.tokens for e in es)
     print(
         f"served {rep.n} requests in {time.perf_counter()-t0:.2f}s  "
-        f"mean_ttft {rep.mean_ttft*1e3:.0f}ms attainment {rep.attainment:.0%}"
+        f"mean_ttft {rep.mean_ttft*1e3:.0f}ms attainment {rep.attainment:.0%}  "
+        f"admitted {sum(e.admitted for e in es)} "
+        f"decode_steps {sum(e.decode_steps for e in es)} "
+        f"host_syncs/token {syncs / max(tokens, 1):.2f}"
     )
     if len(finished) < args.requests:
         raise SystemExit(

@@ -12,6 +12,14 @@ sequences free their slot immediately and queued requests are admitted at
 the next step boundary.  ``loaded_layers`` tracks live-scaling progress: a
 partially-loaded engine reports ``can_serve_alone() == False`` and the live
 execution scheduler routes its work through cooperative execution instead.
+
+Observability: the host code between the jitted calls is annotated with
+profiler spans (``engine.enqueue``, ``engine.admit``, ``engine.prefill``,
+``engine.readback``, ``engine.splice``, ``engine.decode``,
+``engine.retire``) whose arguments carry the counts at each boundary and
+the request id; they cost one inactive TraceMe each unless a profiler
+trace is running, and read no clock.  ``stats`` (:class:`EngineStats`)
+counts the same events.
 """
 
 from __future__ import annotations
@@ -24,9 +32,11 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models import transformer as TF
 from repro.models.config import ModelConfig
+from repro.obs.metrics import StatBlock
 
 
 @dataclasses.dataclass
@@ -37,6 +47,14 @@ class ServeRequest:
     out_tokens: list[int] = dataclasses.field(default_factory=list)
     slot: int | None = None
     done: bool = False
+
+
+@dataclasses.dataclass
+class EngineStats(StatBlock):
+    admitted: int = 0  # requests spliced into a decode slot
+    decode_steps: int = 0  # ``_decode_all`` calls
+    host_syncs: int = 0  # device->host reads of a sampled token
+    tokens: int = 0  # tokens delivered to requests
 
 
 class InstanceEngine:
@@ -63,7 +81,7 @@ class InstanceEngine:
         self.last_tokens = jnp.zeros((n_slots,), jnp.int32)
         self.slot_live = jnp.zeros((n_slots,), bool)
         self.loaded_layers = cfg.n_layers  # < n_layers while live-scaling
-        self.steps = 0
+        self.stats = EngineStats()
 
         n = self.n_slots
 
@@ -87,6 +105,11 @@ class InstanceEngine:
 
         self._decode_all = _decode_all
         self._prefill_one = _prefill_one
+        # eager updates per splice: the cache leaves with a slot axis, plus
+        # last_tokens and slot_live
+        self._splice_ops = 2 + sum(
+            1 for x in jax.tree.leaves(self.caches) if x.ndim >= 2 and x.shape[1] == n
+        )
 
     # -- live scaling hooks -----------------------------------------------------
     def set_loaded_layers(self, k: int) -> None:
@@ -98,29 +121,41 @@ class InstanceEngine:
     # -- public API --------------------------------------------------------------
     def submit(self, req: ServeRequest) -> None:
         self.queue.append(req)
+        with TraceAnnotation("engine.enqueue", rid=req.rid, depth=len(self.queue)):
+            pass
 
-    def _splice_slot(self, slot: int, one: Any, first_token: int) -> None:
-        """Install a 1-slot prefill cache + its first sampled token into
-        ``slot``.  Shared by local admission and disagg KV-migration admission
-        so both paths are numerically identical."""
+    def _splice_slot(self, req: ServeRequest, one: Any, first_token: int) -> None:
+        """Install a 1-slot prefill cache + its first sampled token into a
+        free slot and make ``req`` live there.  Shared by local admission and
+        disagg KV-migration admission so both paths are numerically
+        identical."""
+        slot = self.free_slots.pop()
+        req.slot = slot
 
         def splice(old, new):
             if old.ndim >= 2 and old.shape[1] == self.n_slots:
                 return old.at[:, slot].set(new[:, 0])
             return old
 
-        self.caches = jax.tree.map(splice, self.caches, one)
-        self.last_tokens = self.last_tokens.at[slot].set(int(first_token))
-        self.slot_live = self.slot_live.at[slot].set(True)
+        with TraceAnnotation("engine.splice", rid=req.rid, slot=slot, ops=self._splice_ops):
+            self.caches = jax.tree.map(splice, self.caches, one)
+            self.last_tokens = self.last_tokens.at[slot].set(int(first_token))
+            self.slot_live = self.slot_live.at[slot].set(True)
+        self.active[slot] = req
+        self.stats.admitted += 1
 
     def _admit(self) -> None:
-        while self.queue and self.free_slots:
-            req = self.queue.popleft()
-            slot = self.free_slots.pop()
-            req.slot = slot
-            nxt, one = self.prefill_only(req)
-            self._splice_slot(slot, one, nxt)
-            self.active[slot] = req
+        if not self.queue:
+            return
+        admitted = 0
+        with TraceAnnotation("engine.admit", queued=len(self.queue),
+                             free=len(self.free_slots)) as span:
+            while self.queue and self.free_slots:
+                req = self.queue.popleft()
+                nxt, one = self.prefill_only(req)
+                self._splice_slot(req, one, nxt)
+                admitted += 1
+            span.set_metadata(admitted=admitted)
 
     # -- disaggregated-serving entry points --------------------------------------
     def prefill_only(self, req: ServeRequest) -> tuple[int, Any]:
@@ -129,10 +164,14 @@ class InstanceEngine:
         On a prefill instance this is the whole job — the returned cache is
         the KV-migration payload; the first token is emitted here (TTFT is a
         prefill-side metric in PD disaggregation)."""
-        tokens = jnp.asarray(req.prompt[None].astype(np.int32))
-        nxt, one = self._prefill_one(self.params, tokens)
-        first = int(nxt[0])
+        with TraceAnnotation("engine.prefill", rid=req.rid, prompt_len=len(req.prompt)):
+            tokens = jnp.asarray(req.prompt[None].astype(np.int32))
+            nxt, one = self._prefill_one(self.params, tokens)
+            with TraceAnnotation("engine.readback", syncs=1, tokens=1):
+                first = int(nxt[0])
         req.out_tokens.append(first)
+        self.stats.host_syncs += 1
+        self.stats.tokens += 1
         return first, one
 
     def admit_prefilled(self, req: ServeRequest, first_token: int, one: Any) -> bool:
@@ -143,10 +182,7 @@ class InstanceEngine:
         decode continues bit-identically from the migrated state."""
         if not self.free_slots:
             return False
-        slot = self.free_slots.pop()
-        req.slot = slot
-        self._splice_slot(slot, one, first_token)
-        self.active[slot] = req
+        self._splice_slot(req, one, first_token)
         return True
 
     def kv_used_frac(self) -> float:
@@ -157,19 +193,31 @@ class InstanceEngine:
         return used / float(self.n_slots * self.max_seq)
 
     def step(self) -> list[ServeRequest]:
-        """One continuous-batching iteration; returns finished requests."""
+        """One continuous-batching iteration; returns finished requests.
+
+        After the decode, one pass reads each live slot's token to the host
+        and a second frees the slots whose requests are done."""
         self._admit()
         finished: list[ServeRequest] = []
         if not self.active:
             return finished
-        nxt, self.caches = self._decode_all(
-            self.params, self.last_tokens, self.caches, self.slot_live
-        )
+        live = list(self.active.items())
+        with TraceAnnotation("engine.decode", live=len(live)):
+            nxt, self.caches = self._decode_all(
+                self.params, self.last_tokens, self.caches, self.slot_live
+            )
         self.last_tokens = nxt
-        self.steps += 1
-        for slot, req in list(self.active.items()):
-            req.out_tokens.append(int(nxt[slot]))
-            if len(req.out_tokens) >= req.max_new_tokens:
+        self.stats.decode_steps += 1
+        with TraceAnnotation("engine.readback", syncs=len(live), tokens=len(live)):
+            for slot, req in live:
+                req.out_tokens.append(int(nxt[slot]))
+        self.stats.host_syncs += len(live)
+        self.stats.tokens += len(live)
+        done = [(slot, req) for slot, req in live if len(req.out_tokens) >= req.max_new_tokens]
+        if not done:
+            return finished
+        with TraceAnnotation("engine.retire", finished=len(done), ops=len(done)):
+            for slot, req in done:
                 req.done = True
                 finished.append(req)
                 self.active.pop(slot)
