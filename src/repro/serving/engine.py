@@ -58,7 +58,13 @@ class EngineStats(StatBlock):
 
 
 class InstanceEngine:
-    """Continuous-batching engine around the unified model."""
+    """Continuous-batching engine around the unified model.
+
+    Every decode step runs all ``n_slots`` slots.  A free slot's cache is
+    scratch: its K/V, state and ``lengths`` keep changing (``lengths`` may
+    pass ``max_seq``, where the append writes nothing) and nothing reads
+    them, until ``_splice_slot`` rewrites every leaf with a slot axis for
+    the whole slot on the next admission."""
 
     def __init__(
         self,
@@ -88,15 +94,7 @@ class InstanceEngine:
         @jax.jit
         def _decode_all(params, last_tokens, caches, live_mask):
             nxt, new_caches = TF.decode_step(cfg, params, last_tokens, caches)
-
-            def sel(new, old):
-                if new.ndim >= 2 and new.shape[1] == n:
-                    shape = (1, n) + (1,) * (new.ndim - 2)
-                    return jnp.where(live_mask.reshape(shape), new, old)
-                return new
-
-            merged = jax.tree.map(sel, new_caches, caches)
-            return jnp.where(live_mask, nxt, last_tokens), merged
+            return jnp.where(live_mask, nxt, last_tokens), new_caches
 
         @jax.jit
         def _prefill_one(params, tokens):
@@ -195,8 +193,10 @@ class InstanceEngine:
     def step(self) -> list[ServeRequest]:
         """One continuous-batching iteration; returns finished requests.
 
-        After the decode, one pass reads each live slot's token to the host
-        and a second frees the slots whose requests are done."""
+        The decode runs every slot; free slots keep their last token and
+        their caches are scratch until a splice rewrites them.  After the
+        decode, one pass reads each live slot's token to the host and a
+        second frees the slots whose requests are done."""
         self._admit()
         finished: list[ServeRequest] = []
         if not self.active:

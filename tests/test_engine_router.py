@@ -52,6 +52,54 @@ def test_engine_batched_equals_sequential():
         assert batched[i] == r.out_tokens
 
 
+def _serve(eng, steps, submits=(), prefill=None):
+    """Step ``eng`` ``steps`` times; ``submits`` maps a step index to the
+    requests handed in before it, through local admission, or through
+    ``prefill.prefill_only`` + ``admit_prefilled`` when ``prefill`` is an
+    engine."""
+    at = dict(submits)
+    for i in range(steps):
+        for r in at.get(i, ()):
+            if prefill is None:
+                eng.submit(r)
+            else:
+                first, one = prefill.prefill_only(r)
+                assert eng.admit_prefilled(r, first, one)
+        eng.step()
+
+
+@pytest.mark.parametrize("admit", ["local", "prefilled"])
+def test_reused_slot_serves_as_fresh_after_its_free_cache_ran_past_max_seq(admit):
+    """A free slot keeps decoding scratch: once its ``lengths`` has passed
+    ``max_seq``, a request spliced into it still gets the tokens it gets on
+    a fresh engine, and the other live slot never sees the slot's past."""
+    max_seq = 64
+    rng = np.random.default_rng(7)
+    pa, pb, pc = (rng.integers(0, CFG.vocab_size, size=n).astype(np.int32) for n in (40, 6, 4))
+    admit_b, steps = 32, 40  # A (slot 0) is done after 2 steps; C (slot 1) runs on
+    prefill = _engine(n_slots=1, max_seq=max_seq) if admit == "prefilled" else None
+
+    eng = _engine(n_slots=2, max_seq=max_seq)
+    a, b, c = ServeRequest(0, pa, 3), ServeRequest(1, pb, 6), ServeRequest(2, pc, 45)
+    _serve(eng, admit_b, {0: [a, c]})
+    assert a.done and a.slot == 0 and c.slot == 1 and 0 not in eng.active
+    lengths = np.asarray(eng.caches["layers"]["lengths"])
+    assert (lengths[:, 0] > max_seq).all() and (lengths[:, 1] < max_seq).all()
+    _serve(eng, steps - admit_b, {0: [b]}, prefill)
+    assert b.done and b.slot == 0 and len(c.out_tokens) == 1 + steps
+
+    alone = _engine(n_slots=2, max_seq=max_seq)
+    b_alone = ServeRequest(1, pb, 6)
+    _serve(alone, 8, {0: [b_alone]})
+    assert b.out_tokens == b_alone.out_tokens
+
+    never_a = _engine(n_slots=2, max_seq=max_seq)
+    b2, c2 = ServeRequest(1, pb, 6), ServeRequest(2, pc, 45)
+    _serve(never_a, admit_b, {0: [c2]})
+    _serve(never_a, steps - admit_b, {0: [b2]}, prefill)
+    assert c.out_tokens == c2.out_tokens and b.out_tokens == b2.out_tokens
+
+
 def test_live_scaling_gate():
     eng = _engine()
     assert eng.can_serve_alone()

@@ -132,6 +132,31 @@ def test_jitted_steps_keep_the_module_names_the_mfu_readers_match(metric):
     assert "HloModule jit_" + fragment + "," in lowered.compile().as_text()
 
 
+def _eqns_outside_scans(jaxpr):
+    """Equations of ``jaxpr`` and of the calls it makes, not entering a scan."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "scan":
+            continue
+        for sub in eqn.params.values():
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                yield from _eqns_outside_scans(sub)
+
+
+def test_decode_step_merges_no_whole_cache_leaf_after_the_layer_scan():
+    """The decode appends inside the layer scan and hands the caches back as
+    they come out of it: no select over a whole stacked cache leaf (a
+    live-slot merge) runs after the scan."""
+    eng = InstanceEngine(CFG, PARAMS, n_slots=3, max_seq=32)
+    jaxpr = jax.make_jaxpr(eng._decode_all)(PARAMS, eng.last_tokens, eng.caches, eng.slot_live)
+    leaf_shapes = {x.shape for x in jax.tree.leaves(eng.caches) if x.ndim >= 2 and x.shape[1] == 3}
+    eqns = list(_eqns_outside_scans(jaxpr.jaxpr))
+    assert any(e.primitive.name == "scan" for e in eqns) and len(leaf_shapes) == 2
+    selects = [v.aval.shape for e in eqns if e.primitive.name == "select_n" for v in e.outvars]
+    assert selects and not leaf_shapes & set(selects)
+
+
 def test_colocated_cli_stamps_ttft_at_the_first_token(monkeypatch, capsys):
     events = []
     note_first, note_done = Router.note_first_token, Router.note_done
