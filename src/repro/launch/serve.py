@@ -302,7 +302,9 @@ def run_colocated(args, cfg, params) -> list[ServeRequest]:
         f"mean_ttft {rep.mean_ttft*1e3:.0f}ms attainment {rep.attainment:.0%}  "
         f"admitted {sum(e.admitted for e in es)} "
         f"decode_steps {sum(e.decode_steps for e in es)} "
-        f"host_syncs/token {syncs / max(tokens, 1):.2f}"
+        f"host_syncs/token {syncs / max(tokens, 1):.2f} "
+        f"ctx_tokens {sum(e.ctx_tokens for e in es)} "
+        f"cache_bytes {sum(e.cache_bytes for e in es)}"
     )
     if len(finished) < args.requests:
         raise SystemExit(

@@ -31,6 +31,13 @@ class ModelConfig:
     rope_theta: float = 10_000.0
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
+    # MiniCPM's muP scalings (1.0 leaves a model's programs unchanged):
+    # embeddings x embed_scale; each attention and MLP branch x
+    # residual_scale before its residual add; the final hidden state x
+    # logit_scale before the LM head
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_scale: float = 1.0
 
     # --- MoE ---------------------------------------------------------------
     n_experts: int = 0

@@ -302,10 +302,13 @@ def vocab_mask_logits(logits: jax.Array, cfg) -> jax.Array:
 
 def embed_tokens(params: dict, tokens: jax.Array, cfg) -> jax.Array:
     out = jnp.take(params["tok"], tokens, axis=0)
+    if cfg.embed_scale != 1.0:
+        out = out * cfg.embed_scale
     return shard(out, "batch", "seq", "act_d_model")
 
 
 def unembed(params: dict, x: jax.Array, cfg) -> jax.Array:
     w = params["tok"].T if cfg.tie_embeddings else params["unembed"]
-    logits = x @ w
-    return logits
+    if cfg.logit_scale != 1.0:
+        x = x * cfg.logit_scale
+    return x @ w

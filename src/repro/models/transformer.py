@@ -115,6 +115,11 @@ def n_layer_blocks(cfg: ModelConfig) -> int:
 # ---------------------------------------------------------------------------
 
 
+def _residual_branch(cfg, branch: jax.Array) -> jax.Array:
+    """An attention or MLP branch as it enters the residual add."""
+    return branch * cfg.residual_scale if cfg.residual_scale != 1.0 else branch
+
+
 def _attn_layer_fwd(
     cfg,
     lp: dict,
@@ -134,7 +139,7 @@ def _attn_layer_fwd(
         a, new_cache = attention.gqa_prefill(
             lp["attn"], h, positions, cfg, causal=causal, cache=cache
         )
-    x = x + a
+    x = x + _residual_branch(cfg, a)
     if enc_out is not None:
         hx = layers.rmsnorm(x, lp["norm_x"], cfg.norm_eps)
         # cross-attention: kv from encoder output, no rope, not causal
@@ -151,7 +156,7 @@ def _attn_layer_fwd(
         m, aux = moe.moe_forward(lp["moe"], h2, cfg)
     else:
         m = layers.mlp_forward(lp["mlp"], h2, cfg)
-    return x + m, new_cache, aux
+    return x + _residual_branch(cfg, m), new_cache, aux
 
 
 def _ssm_layer_fwd(
@@ -182,7 +187,7 @@ def _attn_layer_decode(
         a, new_cache = attention.mla_decode(lp["attn"], h, cfg, cache)
     else:
         a, new_cache = attention.gqa_decode(lp["attn"], h, cfg, cache)
-    x = x + a
+    x = x + _residual_branch(cfg, a)
     if cross_cache is not None:
         hx = layers.rmsnorm(x, lp["norm_x"], cfg.norm_eps)
         ax, _ = attention.gqa_decode(lp["xattn"], hx, cfg, cache, cross_cache=cross_cache)
@@ -192,7 +197,7 @@ def _attn_layer_decode(
         m, _ = moe.moe_forward(lp["moe"], h2, cfg)
     else:
         m = layers.mlp_forward(lp["mlp"], h2, cfg)
-    return x + m, new_cache
+    return x + _residual_branch(cfg, m), new_cache
 
 
 def _ssm_layer_decode(cfg, lp: dict, x: jax.Array, state: dict) -> tuple[jax.Array, dict]:

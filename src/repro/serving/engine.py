@@ -17,7 +17,8 @@ Observability: the host code between the jitted calls is annotated with
 profiler spans (``engine.enqueue``, ``engine.admit``, ``engine.prefill``,
 ``engine.readback``, ``engine.splice``, ``engine.decode``,
 ``engine.retire``) whose arguments carry the counts at each boundary and
-the request id; they cost one inactive TraceMe each unless a profiler
+the request id (``engine.decode``: live slots and the context positions
+they attend over); they cost one inactive TraceMe each unless a profiler
 trace is running, and read no clock.  ``stats`` (:class:`EngineStats`)
 counts the same events.
 """
@@ -55,6 +56,8 @@ class EngineStats(StatBlock):
     decode_steps: int = 0  # ``_decode_all`` calls
     host_syncs: int = 0  # device->host reads of a sampled token
     tokens: int = 0  # tokens delivered to requests
+    ctx_tokens: int = 0  # cache positions the decode steps attended over, live slots
+    cache_bytes: int = 0  # bytes of the slot caches, set once at build
 
 
 class InstanceEngine:
@@ -87,7 +90,8 @@ class InstanceEngine:
         self.last_tokens = jnp.zeros((n_slots,), jnp.int32)
         self.slot_live = jnp.zeros((n_slots,), bool)
         self.loaded_layers = cfg.n_layers  # < n_layers while live-scaling
-        self.stats = EngineStats()
+        self.stats = EngineStats(
+            cache_bytes=sum(x.nbytes for x in jax.tree.leaves(self.caches)))
 
         n = self.n_slots
 
@@ -202,12 +206,16 @@ class InstanceEngine:
         if not self.active:
             return finished
         live = list(self.active.items())
-        with TraceAnnotation("engine.decode", live=len(live)):
+        # positions each live slot attends over in this step: its prompt and
+        # every token served, the last of which the decode appends
+        ctx = sum(len(req.prompt) + len(req.out_tokens) for _, req in live)
+        with TraceAnnotation("engine.decode", live=len(live), ctx_tokens=ctx):
             nxt, self.caches = self._decode_all(
                 self.params, self.last_tokens, self.caches, self.slot_live
             )
         self.last_tokens = nxt
         self.stats.decode_steps += 1
+        self.stats.ctx_tokens += ctx
         with TraceAnnotation("engine.readback", syncs=len(live), tokens=len(live)):
             for slot, req in live:
                 req.out_tokens.append(int(nxt[slot]))

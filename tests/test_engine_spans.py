@@ -104,6 +104,32 @@ def test_span_args_add_up_to_the_counters(colocated):
     assert spans.engine_queue_p90_ms(program) >= 0.0
 
 
+def test_decode_span_carries_the_live_context(colocated):
+    """``ctx_tokens`` of each decode is the positions its live slots attend
+    over: a request's j-th decode (j = 1 .. tokens - 1) attends over its
+    prompt and j served tokens."""
+    eng, reqs, _, program = colocated
+    decodes = _by(program, "engine.decode")
+    want = sum(len(r.prompt) + j for r in reqs for j in range(1, len(r.out_tokens)))
+    assert sum(p[3]["ctx_tokens"] for p in decodes) == eng.stats.ctx_tokens == want
+    assert all(p[3]["ctx_tokens"] >= 9 * p[3]["live"] for p in decodes)  # prompts of 8
+
+
+@pytest.mark.parametrize("arch", ["granite-8b", "minicpm3-4b"])
+def test_cache_bytes_counts_the_slot_caches_once(arch):
+    """GQA holds K and V per KV head; MLA the latent and the shared rope key;
+    both hold one int32 length per slot and layer."""
+    cfg = get_config(arch, reduced=True)
+    eng = InstanceEngine(cfg, TF.init_params(jax.random.PRNGKey(0), cfg), n_slots=3, max_seq=32)
+    if cfg.attn == "mla":
+        per_position = (cfg.kv_lora_rank + cfg.qk_rope_dim) * 2
+    else:
+        per_position = 2 * cfg.n_kv_heads * cfg.resolved_head_dim * 2
+    rows = cfg.n_layers * 3
+    assert eng.stats.cache_bytes == rows * 32 * per_position + rows * 4
+    assert eng.stats.cache_bytes == sum(x.nbytes for x in jax.tree.leaves(eng.caches))
+
+
 def test_disagg_admit_prefilled_emits_splice(tmp_path):
     pre = InstanceEngine(CFG, PARAMS, n_slots=1, max_seq=32)
     dec = InstanceEngine(CFG, PARAMS, n_slots=2, max_seq=32)
@@ -181,3 +207,4 @@ def test_colocated_cli_stamps_ttft_at_the_first_token(monkeypatch, capsys):
     assert [k for k, _ in events[:2]] == ["first", "first"]
     out = capsys.readouterr().out
     assert "decode_steps " in out and "host_syncs/token 1.00" in out
+    assert "ctx_tokens " in out and "cache_bytes " in out
