@@ -54,7 +54,7 @@ class ServeRequest:
 class EngineStats(StatBlock):
     admitted: int = 0  # requests spliced into a decode slot
     decode_steps: int = 0  # ``_decode_all`` calls
-    host_syncs: int = 0  # device->host reads of a sampled token
+    host_syncs: int = 0  # device->host transfers of sampled tokens: one per decode step and prefill
     tokens: int = 0  # tokens delivered to requests
     ctx_tokens: int = 0  # cache positions the decode steps attended over, live slots
     cache_bytes: int = 0  # bytes of the slot caches, set once at build
@@ -170,7 +170,7 @@ class InstanceEngine:
             tokens = jnp.asarray(req.prompt[None].astype(np.int32))
             nxt, one = self._prefill_one(self.params, tokens)
             with TraceAnnotation("engine.readback", syncs=1, tokens=1):
-                first = int(nxt[0])
+                first = int(jax.device_get(nxt)[0])
         req.out_tokens.append(first)
         self.stats.host_syncs += 1
         self.stats.tokens += 1
@@ -199,8 +199,10 @@ class InstanceEngine:
 
         The decode runs every slot; free slots keep their last token and
         their caches are scratch until a splice rewrites them.  After the
-        decode, one pass reads each live slot's token to the host and a
-        second frees the slots whose requests are done."""
+        decode, one device-to-host transfer brings every slot's token to the
+        host, whatever the number of live slots; the live slots' tokens are
+        read from that copy and the slots whose requests are done are
+        freed."""
         self._admit()
         finished: list[ServeRequest] = []
         if not self.active:
@@ -216,10 +218,11 @@ class InstanceEngine:
         self.last_tokens = nxt
         self.stats.decode_steps += 1
         self.stats.ctx_tokens += ctx
-        with TraceAnnotation("engine.readback", syncs=len(live), tokens=len(live)):
+        with TraceAnnotation("engine.readback", syncs=1, tokens=len(live)):
+            toks = jax.device_get(nxt)
             for slot, req in live:
-                req.out_tokens.append(int(nxt[slot]))
-        self.stats.host_syncs += len(live)
+                req.out_tokens.append(int(toks[slot]))
+        self.stats.host_syncs += 1
         self.stats.tokens += len(live)
         done = [(slot, req) for slot, req in live if len(req.out_tokens) >= req.max_new_tokens]
         if not done:

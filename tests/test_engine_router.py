@@ -1,6 +1,9 @@
 """Serving engine (continuous batching) + router + paged KV cache."""
 
+import contextlib
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -36,6 +39,17 @@ def test_continuous_batching_completes_all_requests():
         assert all(0 <= t < CFG.vocab_size for t in r.out_tokens)
 
 
+def _sequential(prompts, max_new):
+    """Each request's tokens served alone, on a fresh one-slot engine."""
+    out = []
+    for i, (p, n) in enumerate(zip(prompts, max_new)):
+        eng = _engine(n_slots=1)
+        eng.submit(ServeRequest(i, p, n))
+        (r,) = eng.run_until_done()
+        out.append(r.out_tokens)
+    return out
+
+
 @pytest.mark.slow
 def test_engine_batched_equals_sequential():
     """Slot interleaving must not change any request's tokens."""
@@ -44,12 +58,60 @@ def test_engine_batched_equals_sequential():
     for i, p in enumerate(prompts):
         eng_b.submit(ServeRequest(i, p, 5))
     batched = {r.rid: r.out_tokens for r in eng_b.run_until_done()}
+    assert [batched[i] for i in range(3)] == _sequential(prompts, [5] * 3)
 
-    for i, p in enumerate(prompts):
-        eng_s = _engine(n_slots=1)
-        eng_s.submit(ServeRequest(i, p, 5))
-        (r,) = eng_s.run_until_done()
-        assert batched[i] == r.out_tokens
+
+@contextlib.contextmanager
+def _explicit_reads_only(monkeypatch):
+    """Refuse every implicit device-to-host read and count the explicit
+    ones (``jax.device_get`` calls).  The transfer guard refuses implicit
+    reads on an accelerator; a CPU array is read without a copy, which the
+    guard does not see, so every host read of a ``jax.Array`` (its
+    ``_value``, behind ``int()``, ``np.asarray`` and ``jax.device_get``
+    alike) is also refused unless a ``jax.device_get`` is under way.
+    Yields the shapes of the arrays read explicitly, in order."""
+    reads, inside = [], [False]
+    device_get, value = jax.device_get, type(jnp.zeros(1))._value
+
+    def counted_get(x):
+        reads.append(x.shape)
+        inside[0] = True
+        try:
+            return device_get(x)
+        finally:
+            inside[0] = False
+
+    def guarded_value(self):
+        assert inside[0], "implicit device-to-host read"
+        return value.fget(self)
+
+    monkeypatch.setattr(jax, "device_get", counted_get)
+    monkeypatch.setattr(type(jnp.zeros(1)), "_value", property(guarded_value))
+    with jax.transfer_guard_device_to_host("disallow"):
+        yield reads
+    monkeypatch.undo()
+
+
+def test_engine_reads_each_step_in_one_explicit_transfer(monkeypatch):
+    """Three slots live at once, a fourth request queued for a freed slot:
+    through ``submit``, ``step`` and retirement no device array is read
+    implicitly, each prefill and each decode step reads its tokens in one
+    ``jax.device_get``, and the tokens are those each request gets alone."""
+    prompts = [np.arange(5, dtype=np.int32) + i for i in range(4)]
+    max_new = [5, 3, 6, 4]
+    eng = _engine(n_slots=3)
+    with _explicit_reads_only(monkeypatch) as reads:
+        for i, p in enumerate(prompts):
+            eng.submit(ServeRequest(i, p, max_new[i]))
+        done = eng.step()
+        assert len(eng.active) == 3 and len(eng.queue) == 1
+        done += eng.run_until_done()
+    served = {r.rid: r.out_tokens for r in done}
+    # a (1,) read per prefill, a (3,) read per decode step
+    assert sorted(set(reads)) == [(1,), (3,)] and reads.count((1,)) == len(prompts)
+    assert len(reads) == eng.stats.decode_steps + len(prompts) == eng.stats.host_syncs
+    assert eng.stats.admitted == len(prompts) and not eng.active
+    assert [served[i] for i in range(4)] == _sequential(prompts, max_new)
 
 
 def _serve(eng, steps, submits=(), prefill=None):

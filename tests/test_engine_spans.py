@@ -5,6 +5,7 @@ readers match; and the colocated CLI's TTFT stamp."""
 
 import argparse
 import os
+import re
 import sys
 
 import jax
@@ -92,11 +93,22 @@ def test_every_admitted_request_has_nested_spans(colocated):
 
 
 def test_span_args_add_up_to_the_counters(colocated):
+    """One device-to-host transfer per decode step and one per prefill,
+    whatever the number of live slots: each decode's readback carries one
+    sync and a token for each of its live slots."""
     eng, reqs, _, program = colocated
     rb = _by(program, "engine.readback")
-    assert sum(p[3]["syncs"] for p in rb) == eng.stats.host_syncs
+    n_syncs = eng.stats.decode_steps + len(reqs)  # one prefill per request
+    assert sum(p[3]["syncs"] for p in rb) == eng.stats.host_syncs == n_syncs
     assert sum(p[3]["tokens"] for p in rb) == eng.stats.tokens == sum(len(r.out_tokens) for r in reqs)
-    assert spans.host_syncs_per_token(program) == 1.0
+    decodes = _by(program, "engine.decode")
+    prefills = _by(program, "engine.prefill")
+    step_rb = [b for b in rb if not any(_inside(b, p) for p in prefills)]
+    assert len(step_rb) == len(decodes)
+    for d, b in zip(decodes, step_rb):
+        assert d[2] <= b[1] and b[3] == {"syncs": 1, "tokens": d[3]["live"]}
+    assert max(d[3]["live"] for d in decodes) == eng.n_slots
+    assert spans.host_syncs_per_token(program) == n_syncs / eng.stats.tokens < 1.0
     assert eng.stats.decode_steps == len(_by(program, "engine.decode"))
     assert eng.stats.admitted == len(_by(program, "engine.splice")) == len(reqs)
     assert sum(p[3]["finished"] for p in _by(program, "engine.retire")) == len(reqs)
@@ -206,5 +218,9 @@ def test_colocated_cli_stamps_ttft_at_the_first_token(monkeypatch, capsys):
     # before they finish
     assert [k for k, _ in events[:2]] == ["first", "first"]
     out = capsys.readouterr().out
-    assert "decode_steps " in out and "host_syncs/token 1.00" in out
+    # one host sync per decode step and per prefill, over the 4 x 4 tokens served
+    admitted = int(re.search(r"admitted (\d+) ", out).group(1))
+    steps = int(re.search(r"decode_steps (\d+) ", out).group(1))
+    assert admitted == 4 and steps >= 6
+    assert f"host_syncs/token {(steps + admitted) / 16:.2f} " in out
     assert "ctx_tokens " in out and "cache_bytes " in out
