@@ -36,7 +36,7 @@ def flash_rows():
         t0 = time.perf_counter()
         out = flash_attention(q, k, v, causal=True, block_q=bq, block_k=bk, interpret=True)
         dt = time.perf_counter() - t0
-        err = float(jnp.max(jnp.abs(out - ref.flash_attention_ref(q, k, v, causal=True))))
+        err = float(jnp.max(jnp.abs(out - ref.attention_reference(q, k, v, causal=True))))
         vmem = (bq * d + 2 * bk * d) * 2 + bq * bk * 4 + bq * d * 4  # bytes
         flops = 4.0 * s * s * h * d / 2  # causal half
         rows.append([f"{b}x{s}x{h}x{d}", f"{bq}/{bk}", round(vmem / 1024, 1),
@@ -56,7 +56,7 @@ def decode_rows():
         t0 = time.perf_counter()
         out = decode_attention(q, kc, vc, lengths, block_s=bs, interpret=True)
         dt = time.perf_counter() - t0
-        err = float(jnp.max(jnp.abs(out - ref.decode_attention_ref(q, kc, vc, lengths))))
+        err = float(jnp.max(jnp.abs(out - ref.decode_attention_reference(q, kc, vc, lengths))))
         vmem = 2 * bs * d * 2 + (h // kv) * bs * 4
         ai = (2.0 * h * d) / (2 * d * 2)  # flops per cache byte ~ n_rep/1
         rows.append([f"{b}x{s}x{h}x{d}", bs, round(vmem / 1024, 1),
@@ -74,7 +74,7 @@ def rmsnorm_rows():
         t0 = time.perf_counter()
         out = fused_rmsnorm(x, w, block_n=bn, interpret=True)
         dt = time.perf_counter() - t0
-        err = float(jnp.max(jnp.abs(out - ref.rmsnorm_ref(x, w))))
+        err = float(jnp.max(jnp.abs(out - ref.rmsnorm(x, w))))
         rows.append([f"{shape}", bn, f"{err:.2e}", round(dt * 1e3, 1)])
     return rows
 

@@ -24,7 +24,6 @@ CONFIG = ModelConfig(
     # kv=16 divides the 16-way model axis exactly -> head-sharded KV cache
     # beats the default seq-sharded cache (no softmax-stat combine needed)
     sharding_overrides={"cache_kv_heads": "model", "cache_seq": None},
-    uniform_decode=True,  # cache seq unsharded -> scalar-DUS append is in-place (C2)
     microbatches=16,
 )
 

@@ -31,7 +31,6 @@ CONFIG = ModelConfig(
     attn_every=6,
     # kv=32 divides the 16-way model axis -> head-sharded shared-attn cache
     sharding_overrides={"cache_kv_heads": "model", "cache_seq": None},
-    uniform_decode=True,  # cache seq unsharded -> scalar-DUS append is in-place (C2)
     microbatches=16,
 )
 

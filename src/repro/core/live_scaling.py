@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.zigzag import live_throughput_multiplier
+from repro.kernels.ref import rmsnorm
 from repro.models import layers as L
 from repro.models import transformer as TF
 from repro.models.config import ModelConfig
@@ -117,7 +118,7 @@ def cooperative_forward(
     x = TF.forward_layers_range(
         cfg, params["layers"], x, k, cfg.n_layers, positions, shared
     )
-    x = L.rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     return L.unembed(params["embed"], x, cfg)
 
 

@@ -39,7 +39,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from repro.kernels.ref import NEG_INF
 
 
 def _flash_kernel(

@@ -38,7 +38,7 @@ def flash_attention(
 ):
     """impl: 'pallas' | 'ref'."""
     if impl == "ref":
-        return ref.flash_attention_ref(q, k, v, causal=causal)
+        return ref.attention_reference(q, k, v, causal=causal)
     _check_compiled_backend(interpret)
     return _flash_pallas(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k, interpret=interpret
@@ -51,7 +51,7 @@ def decode_attention(
     interpret: bool = False,
 ):
     if impl == "ref":
-        return ref.decode_attention_ref(q, k_cache, v_cache, lengths)
+        return ref.decode_attention_reference(q, k_cache, v_cache, lengths)
     _check_compiled_backend(interpret)
     return _decode_pallas(
         q, k_cache, v_cache, lengths, block_s=block_s, interpret=interpret
@@ -64,6 +64,6 @@ def rmsnorm(
     interpret: bool = False,
 ):
     if impl == "ref":
-        return ref.rmsnorm_ref(x, w, eps)
+        return ref.rmsnorm(x, w, eps)
     _check_compiled_backend(interpret)
     return _rmsnorm_pallas(x, w, eps, block_n=block_n, interpret=interpret)

@@ -196,12 +196,8 @@ def build_decode_artifacts(cfg: ModelConfig, sp: ShapeSpec, rules: sh.ShardingRu
     )
 
 
-def build_cell(arch: str, shape: str, mesh, *, cfg_overrides: dict | None = None) -> StepArtifacts:
-    """cfg_overrides: §Perf variant knobs (e.g. {"kv_quant": True}) applied
-    on top of the registered config — baselines never set this."""
+def build_cell(arch: str, shape: str, mesh) -> StepArtifacts:
     cfg = get_config(arch)
-    if cfg_overrides:
-        cfg = cfg.replace(**cfg_overrides)
     sp = SHAPES[shape]
     rules = make_rules(cfg, mesh)
     if sp.kind == "train":

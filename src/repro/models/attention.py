@@ -16,15 +16,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.distributed.sharding import TensorSpec, seq_sharded, shard
+from repro.kernels.ref import NEG_INF, chunked_attention, decode_attention_reference, rmsnorm
 from repro.models import kvcache
-from repro.models.layers import (
-    apply_rope,
-    attention_reference,
-    chunked_attention,
-    decode_attention_reference,
-    rmsnorm,
-    rope_for,
-)
+from repro.models.layers import apply_rope, rope_for
 
 # ---------------------------------------------------------------------------
 # GQA
@@ -124,11 +118,9 @@ def gqa_decode(
             cos, sin = rope_for(pos, hd, cfg.rope_theta)
             q = apply_rope(q[:, None], cos, sin)[:, 0]
             k = apply_rope(k[:, None], cos, sin)[:, 0]
-        append = kvcache.append_kv_uniform if cfg.uniform_decode else kvcache.append_kv
-        new_cache = append(cache, k, v)
+        new_cache = kvcache.append_kv(cache, k, v)
         out = decode_attention_reference(
-            q, new_cache["k"], new_cache["v"], new_cache["lengths"],
-            k_scale=new_cache.get("k_scale"), v_scale=new_cache.get("v_scale"),
+            q, new_cache["k"], new_cache["v"], new_cache["lengths"]
         )
     out = jnp.einsum("bhk,hkd->bd", out, params["wo"])[:, None]
     return shard(out, "batch", "seq", "act_d_model"), new_cache
@@ -245,8 +237,7 @@ def mla_decode(
     pos = cache["lengths"][:, None]  # (B, 1)
     q_nope, q_rope = _mla_q(params, x, pos, cfg)  # (B, 1, H, ·)
     c_new, kr_new = _mla_ckv(params, x, pos, cfg)
-    append = kvcache.append_mla_uniform if cfg.uniform_decode else kvcache.append_mla
-    new_cache = append(cache, c_new[:, 0], kr_new[:, 0])
+    new_cache = kvcache.append_mla(cache, c_new[:, 0], kr_new[:, 0])
 
     # absorbed decode: score = q_nope·(W_uk·c) + q_rope·kr
     #                        = (q_nope·W_uk)·c + q_rope·kr
@@ -261,7 +252,7 @@ def mla_decode(
     s = (s_latent + s_rope) * scale
     smax = new_cache["ckv"].shape[1]
     valid = jnp.arange(smax)[None, :] < new_cache["lengths"][:, None]
-    s = jnp.where(valid[:, None, :], s, -1e30)
+    s = jnp.where(valid[:, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     # out = p · v = p · (W_uv·c): absorb on the output side too
     ctx = jnp.einsum("bhs,bsr->bhr", p, new_cache["ckv"].astype(jnp.float32))  # (B, H, kvlr)

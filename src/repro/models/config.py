@@ -78,17 +78,6 @@ class ModelConfig:
 
     max_seq: int = 131_072
     dtype: Any = jnp.bfloat16
-    # lockstep decode positions: a scalar-index cache append partitions in
-    # place — but ONLY when the cache seq dim is unsharded (head-sharded
-    # caches: olmoe, zamba2).  On seq-SHARDED caches GSPMD lowers a scalar
-    # DUS through its "last resort" replication path and the step REGRESSES
-    # (granite decode_32k: 37.5 -> 55.2 ms, §Perf C2 — refuted there), so
-    # the default stays on the masked-where append.
-    uniform_decode: bool = False
-    # int8 KV cache with per-(token, kv-head) absmax scales (§Perf C3):
-    # halves cache-proportional HBM traffic and doubles KV capacity; the
-    # dequant converts fuse into the attention dot reads on TPU.
-    kv_quant: bool = False
 
     # sharding rule overrides for this arch (e.g. FSDP for >=100B)
     sharding_overrides: Mapping[str, Any] | None = None

@@ -3,86 +3,53 @@
 All caches are plain pytrees (dicts of arrays) so they flow through jit /
 pjit / scan unchanged and can be sharded with PartitionSpecs.  ``lengths`` is
 per-sequence so continuous batching can mix requests at different decode
-depths in one batch.
+depths in one batch: each append writes each row at that row's own length.
 
-A paged variant (block tables) backs the serving engine; a property test
-asserts paged == contiguous numerics.
+The serving engine holds one contiguous ``max_seq`` cache per slot.
+``PagedKVCache`` (block tables, host numpy) is not used by the engine; only
+tests use it, and a device-side paged cache is future work.
 """
 
 from __future__ import annotations
-
-from typing import Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.distributed.sharding import shard
-
 
 # ---------------------------------------------------------------------------
 # Contiguous GQA KV cache
 # ---------------------------------------------------------------------------
-#
-# Optional int8 quantization (§Perf C3): values are stored as
-# round(x / s * 127) int8 with per-(batch, kv-head, token) absmax scales
-# (B, KV, S) f32.  Dequantization multiplies the attention scores (for K)
-# and the combine probabilities (for V) — exact per-token scaling, no
-# materialized dequantized cache.
 
 
-def quantize_kv(x: jax.Array, axis: int = -1) -> tuple[jax.Array, jax.Array]:
-    """x -> (int8 values, f32 scales) with absmax scaling along `axis`."""
-    amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=axis)
-    scale = jnp.maximum(amax, 1e-8) / 127.0
-    q = jnp.round(x.astype(jnp.float32) / scale[..., None]).astype(jnp.int8)
-    return q, scale
-
-
-def init_kv_cache(batch: int, max_seq: int, n_kv: int, head_dim: int, dtype,
-                  *, quant: bool = False) -> dict:
+def init_kv_cache(batch: int, max_seq: int, n_kv: int, head_dim: int, dtype) -> dict:
     """Cache layout is (B, KV, S, D) — seq-major per KV head.  The decode
     dot contracts D with batch dims (B, KV), so this layout feeds the MXU
     directly; the (B, S, KV, D) activation layout would force a physical
     transpose copy of the whole cache every layer (§Perf C1: ~12 ms/step for
     granite decode_32k)."""
-    vdtype = jnp.int8 if quant else dtype
-    out = {
-        "k": jnp.zeros((batch, n_kv, max_seq, head_dim), vdtype),
-        "v": jnp.zeros((batch, n_kv, max_seq, head_dim), vdtype),
+    return {
+        "k": jnp.zeros((batch, n_kv, max_seq, head_dim), dtype),
+        "v": jnp.zeros((batch, n_kv, max_seq, head_dim), dtype),
         "lengths": jnp.zeros((batch,), jnp.int32),
     }
-    if quant:
-        out["k_scale"] = jnp.zeros((batch, n_kv, max_seq), jnp.float32)
-        out["v_scale"] = jnp.zeros((batch, n_kv, max_seq), jnp.float32)
-    return out
 
 
-def kv_cache_abstract(batch: int, max_seq: int, n_kv: int, head_dim: int, dtype,
-                      *, quant: bool = False) -> dict:
-    vdtype = jnp.int8 if quant else dtype
-    out = {
-        "k": jax.ShapeDtypeStruct((batch, n_kv, max_seq, head_dim), vdtype),
-        "v": jax.ShapeDtypeStruct((batch, n_kv, max_seq, head_dim), vdtype),
+def kv_cache_abstract(batch: int, max_seq: int, n_kv: int, head_dim: int, dtype) -> dict:
+    return {
+        "k": jax.ShapeDtypeStruct((batch, n_kv, max_seq, head_dim), dtype),
+        "v": jax.ShapeDtypeStruct((batch, n_kv, max_seq, head_dim), dtype),
         "lengths": jax.ShapeDtypeStruct((batch,), jnp.int32),
     }
-    if quant:
-        out["k_scale"] = jax.ShapeDtypeStruct((batch, n_kv, max_seq), jnp.float32)
-        out["v_scale"] = jax.ShapeDtypeStruct((batch, n_kv, max_seq), jnp.float32)
-    return out
 
 
-def kv_cache_axes(*, quant: bool = False) -> dict:
+def kv_cache_axes() -> dict:
     """Logical axes for sharding the cache."""
-    out = {
+    return {
         "k": ("cache_batch", "cache_kv_heads", "cache_seq", "head_dim"),
         "v": ("cache_batch", "cache_kv_heads", "cache_seq", "head_dim"),
         "lengths": ("cache_batch",),
     }
-    if quant:
-        out["k_scale"] = ("cache_batch", "cache_kv_heads", "cache_seq")
-        out["v_scale"] = ("cache_batch", "cache_kv_heads", "cache_seq")
-    return out
 
 
 def write_prompt_kv(cache: dict, k: jax.Array, v: jax.Array, lengths: jax.Array) -> dict:
@@ -91,49 +58,8 @@ def write_prompt_kv(cache: dict, k: jax.Array, v: jax.Array, lengths: jax.Array)
     kt = k.transpose(0, 2, 1, 3)  # (B, KV, S, D)
     vt = v.transpose(0, 2, 1, 3)
     out = {"lengths": lengths.astype(jnp.int32)}
-    if "k_scale" in cache:
-        kq, ks = quantize_kv(kt)
-        vq, vs = quantize_kv(vt)
-        out["k"] = jax.lax.dynamic_update_slice(cache["k"], kq, (0, 0, 0, 0))
-        out["v"] = jax.lax.dynamic_update_slice(cache["v"], vq, (0, 0, 0, 0))
-        out["k_scale"] = jax.lax.dynamic_update_slice(cache["k_scale"], ks, (0, 0, 0))
-        out["v_scale"] = jax.lax.dynamic_update_slice(cache["v_scale"], vs, (0, 0, 0))
-        return out
     out["k"] = jax.lax.dynamic_update_slice(cache["k"], kt.astype(cache["k"].dtype), (0, 0, 0, 0))
     out["v"] = jax.lax.dynamic_update_slice(cache["v"], vt.astype(cache["v"].dtype), (0, 0, 0, 0))
-    return out
-
-
-def append_kv_uniform(cache: dict, k_new: jax.Array, v_new: jax.Array) -> dict:
-    """Lockstep append (§Perf C2): all sequences write at the SAME seq
-    position (the batch max length).  A dynamic-update-slice at a traced
-    *scalar* index partitions cleanly under GSPMD (each seq shard checks
-    ownership and writes one row in place) — unlike the per-row masked
-    ``where``, which rewrites the whole cache slice every step (~20 ms of
-    granite decode_32k's 37.5 ms baseline).  Production engines keep decode
-    slots position-aligned for exactly this reason; exact when all lengths
-    are equal (the dry-run serve cells), and the attention mask additionally
-    admits the shared write position for stragglers."""
-    pos = jnp.max(cache["lengths"])  # traced scalar
-
-    def write(buf, new):  # buf: (B, KV, S, D); new: (B, KV, D)
-        return jax.lax.dynamic_update_slice(
-            buf, new[:, :, None, :].astype(buf.dtype), (0, 0, pos, 0)
-        )
-
-    out = {"lengths": cache["lengths"] + 1}
-    if "k_scale" in cache:
-        kq, ks = quantize_kv(k_new)
-        vq, vs = quantize_kv(v_new)
-        out["k"] = write(cache["k"], kq)
-        out["v"] = write(cache["v"], vq)
-        out["k_scale"] = jax.lax.dynamic_update_slice(
-            cache["k_scale"], ks[:, :, None], (0, 0, pos))
-        out["v_scale"] = jax.lax.dynamic_update_slice(
-            cache["v_scale"], vs[:, :, None], (0, 0, pos))
-        return out
-    out["k"] = write(cache["k"], k_new)
-    out["v"] = write(cache["v"], v_new)
     return out
 
 
@@ -153,15 +79,6 @@ def append_kv(cache: dict, k_new: jax.Array, v_new: jax.Array) -> dict:
         return jnp.where(mask, new[:, :, None, :].astype(buf.dtype), buf)
 
     out = {"lengths": idx + 1}
-    if "k_scale" in cache:
-        kq, ks = quantize_kv(k_new)  # (B, KV, D) -> int8 + (B, KV)
-        vq, vs = quantize_kv(v_new)
-        out["k"] = write(cache["k"], kq)
-        out["v"] = write(cache["v"], vq)
-        smask = mask[..., 0]
-        out["k_scale"] = jnp.where(smask, ks[:, :, None], cache["k_scale"])
-        out["v_scale"] = jnp.where(smask, vs[:, :, None], cache["v_scale"])
-        return out
     out["k"] = write(cache["k"], k_new)
     out["v"] = write(cache["v"], v_new)
     return out
@@ -193,22 +110,6 @@ def mla_cache_axes() -> dict:
         "ckv": ("cache_batch", "cache_seq", None),
         "krope": ("cache_batch", "cache_seq", None),
         "lengths": ("cache_batch",),
-    }
-
-
-def append_mla_uniform(cache: dict, ckv_new: jax.Array, krope_new: jax.Array) -> dict:
-    """Lockstep MLA append — see ``append_kv_uniform`` (§Perf C2)."""
-    pos = jnp.max(cache["lengths"])
-
-    def write(buf, new):  # buf: (B, S, R); new: (B, R)
-        return jax.lax.dynamic_update_slice(
-            buf, new[:, None, :].astype(buf.dtype), (0, pos, 0)
-        )
-
-    return {
-        "ckv": write(cache["ckv"], ckv_new),
-        "krope": write(cache["krope"], krope_new),
-        "lengths": cache["lengths"] + 1,
     }
 
 
@@ -261,15 +162,16 @@ def ssm_state_axes() -> dict:
 
 
 # ---------------------------------------------------------------------------
-# Paged KV cache (serving engine; vLLM-style block tables)
+# Paged KV cache (vLLM-style block tables; not used by the serving engine)
 # ---------------------------------------------------------------------------
 
 
 class PagedKVCache:
     """Host-managed paged cache: a pool of fixed-size blocks plus per-request
-    block tables.  Gathers into contiguous form for the jitted decode step —
-    the serving engine uses this to admit/evict requests without copying
-    whole caches.  (Numerics identical to the contiguous cache; see tests.)
+    block tables, gathered into contiguous form on request.  Numerics are
+    identical to the contiguous cache (see tests).  ``InstanceEngine`` does
+    not use it: its slots hold contiguous ``max_seq`` caches.  A paged cache
+    on the device, with prefix reuse, is future work.
     """
 
     def __init__(self, n_blocks: int, block_size: int, n_kv: int, head_dim: int, dtype):

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.distributed.sharding import TensorSpec, shard
-from repro.models.layers import rmsnorm
+from repro.kernels.ref import rmsnorm
 
 
 def mamba2_template(cfg) -> dict[str, TensorSpec]:

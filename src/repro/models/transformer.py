@@ -38,6 +38,7 @@ from repro.distributed.sharding import (
     shard,
     stack_template,
 )
+from repro.kernels.ref import rmsnorm
 from repro.models import attention, kvcache, layers, mamba2, moe
 from repro.models.config import ModelConfig
 
@@ -132,7 +133,7 @@ def _attn_layer_fwd(
     enc_lengths: jax.Array | None = None,
 ) -> tuple[jax.Array, dict | None, jax.Array]:
     """Full-sequence attention layer. Returns (x, new_cache, aux_loss)."""
-    h = layers.rmsnorm(x, lp["norm1"], cfg.norm_eps)
+    h = rmsnorm(x, lp["norm1"], cfg.norm_eps)
     if cfg.attn == "mla":
         a, new_cache = attention.mla_prefill(lp["attn"], h, positions, cfg, cache=cache)
     else:
@@ -141,7 +142,7 @@ def _attn_layer_fwd(
         )
     x = x + _residual_branch(cfg, a)
     if enc_out is not None:
-        hx = layers.rmsnorm(x, lp["norm_x"], cfg.norm_eps)
+        hx = rmsnorm(x, lp["norm_x"], cfg.norm_eps)
         # cross-attention: kv from encoder output, no rope, not causal
         kx = jnp.einsum("bsd,dhk->bshk", enc_out, lp["xattn"]["wk"])
         vx = jnp.einsum("bsd,dhk->bshk", enc_out, lp["xattn"]["wv"])
@@ -150,7 +151,7 @@ def _attn_layer_fwd(
             kv_override=(kx, vx),
         )
         x = x + ax
-    h2 = layers.rmsnorm(x, lp["norm2"], cfg.norm_eps)
+    h2 = rmsnorm(x, lp["norm2"], cfg.norm_eps)
     aux = jnp.zeros((), jnp.float32)
     if cfg.n_experts:
         m, aux = moe.moe_forward(lp["moe"], h2, cfg)
@@ -162,7 +163,7 @@ def _attn_layer_fwd(
 def _ssm_layer_fwd(
     cfg, lp: dict, x: jax.Array, *, state: dict | None = None
 ) -> tuple[jax.Array, dict | None]:
-    h = layers.rmsnorm(x, lp["norm1"], cfg.norm_eps)
+    h = rmsnorm(x, lp["norm1"], cfg.norm_eps)
     if state is None:
         out = mamba2.mamba2_forward(lp["mixer"], h, cfg)
         return x + out, None
@@ -182,17 +183,17 @@ def _attn_layer_decode(
     cache: dict,
     cross_cache: dict | None = None,
 ) -> tuple[jax.Array, dict]:
-    h = layers.rmsnorm(x, lp["norm1"], cfg.norm_eps)
+    h = rmsnorm(x, lp["norm1"], cfg.norm_eps)
     if cfg.attn == "mla":
         a, new_cache = attention.mla_decode(lp["attn"], h, cfg, cache)
     else:
         a, new_cache = attention.gqa_decode(lp["attn"], h, cfg, cache)
     x = x + _residual_branch(cfg, a)
     if cross_cache is not None:
-        hx = layers.rmsnorm(x, lp["norm_x"], cfg.norm_eps)
+        hx = rmsnorm(x, lp["norm_x"], cfg.norm_eps)
         ax, _ = attention.gqa_decode(lp["xattn"], hx, cfg, cache, cross_cache=cross_cache)
         x = x + ax
-    h2 = layers.rmsnorm(x, lp["norm2"], cfg.norm_eps)
+    h2 = rmsnorm(x, lp["norm2"], cfg.norm_eps)
     if cfg.n_experts:
         m, _ = moe.moe_forward(lp["moe"], h2, cfg)
     else:
@@ -201,7 +202,7 @@ def _attn_layer_decode(
 
 
 def _ssm_layer_decode(cfg, lp: dict, x: jax.Array, state: dict) -> tuple[jax.Array, dict]:
-    h = layers.rmsnorm(x, lp["norm1"], cfg.norm_eps)
+    h = rmsnorm(x, lp["norm1"], cfg.norm_eps)
     out, new_state = mamba2.mamba2_decode(lp["mixer"], h, cfg, state)
     return x + out, new_state
 
@@ -217,10 +218,8 @@ def init_caches(cfg: ModelConfig, batch: int, max_seq: int, *, abstract: bool = 
 
     def kv(b, s):
         if abstract:
-            return kvcache.kv_cache_abstract(
-                b, s, cfg.n_kv_heads, hd, cfg.dtype, quant=cfg.kv_quant)
-        return kvcache.init_kv_cache(
-            b, s, cfg.n_kv_heads, hd, cfg.dtype, quant=cfg.kv_quant)
+            return kvcache.kv_cache_abstract(b, s, cfg.n_kv_heads, hd, cfg.dtype)
+        return kvcache.init_kv_cache(b, s, cfg.n_kv_heads, hd, cfg.dtype)
 
     def mla(b, s):
         if abstract:
@@ -285,14 +284,14 @@ def cache_axes(cfg: ModelConfig) -> dict:
     fam = cfg.family
     if fam in ("dense", "vlm", "moe"):
         base = (kvcache.mla_cache_axes() if cfg.attn == "mla"
-                else kvcache.kv_cache_axes(quant=cfg.kv_quant))
+                else kvcache.kv_cache_axes())
         return {"layers": add_layer(base)}
     if fam == "ssm":
         return {"layers": add_layer(kvcache.ssm_state_axes())}
     if fam == "hybrid":
         return {
             "layers": add_layer(kvcache.ssm_state_axes()),
-            "shared": add_layer(kvcache.kv_cache_axes(quant=cfg.kv_quant)),
+            "shared": add_layer(kvcache.kv_cache_axes()),
         }
     if fam == "encdec":
         return {
@@ -329,7 +328,7 @@ def _run_encoder(cfg, params, frames):
         return x, None
 
     x, _ = jax.lax.scan(body, x, params["encoder"])
-    return layers.rmsnorm(x, params["enc_norm"], cfg.norm_eps)
+    return rmsnorm(x, params["enc_norm"], cfg.norm_eps)
 
 
 # ---------------------------------------------------------------------------
@@ -391,7 +390,7 @@ def train_forward(
     else:
         raise ValueError(fam)
 
-    x = layers.rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = layers.unembed(params["embed"], x, cfg)
     return logits, aux
 
@@ -486,7 +485,7 @@ def prefill(
     else:
         raise ValueError(fam)
 
-    x = layers.rmsnorm(x[:, -1:], params["final_norm"], cfg.norm_eps)
+    x = rmsnorm(x[:, -1:], params["final_norm"], cfg.norm_eps)
     logits = layers.unembed(params["embed"], x, cfg)[:, 0]
     logits = layers.vocab_mask_logits(logits.astype(jnp.float32), cfg)
     return jnp.argmax(logits, axis=-1).astype(jnp.int32), new_caches
@@ -566,7 +565,7 @@ def decode_step(
     else:
         raise ValueError(fam)
 
-    x = layers.rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = layers.unembed(params["embed"], x, cfg)[:, 0]
     logits = layers.vocab_mask_logits(logits.astype(jnp.float32), cfg)
     return jnp.argmax(logits, axis=-1).astype(jnp.int32), new_caches

@@ -94,13 +94,13 @@ class DeviceTimeLedger:
 
     # -- views ---------------------------------------------------------------
     def total(self) -> float:
-        """Accounted device-seconds.  Summed in DEVICE_STATES order — the
-        same floats in the same order as ``sum(breakdown().values())``, so
-        the conservation check is exact, not within-epsilon."""
-        t = 0.0
-        for s in DEVICE_STATES:
-            t += self._totals[s]
-        return t
+        """Accounted device-seconds: the builtin ``sum`` over the per-state
+        totals in DEVICE_STATES order, the same call on the same floats as
+        ``sum(breakdown().values())``, so the conservation check is exact,
+        not within-epsilon.  (From Python 3.12 ``sum`` of floats is
+        compensated, so a plain ``+=`` loop can differ from it in the last
+        bit.)"""
+        return sum(self._totals[s] for s in DEVICE_STATES)
 
     def breakdown(self) -> dict[str, float]:
         """Per-state totals, every state present, DEVICE_STATES order."""

@@ -1,11 +1,13 @@
 """Per-instance serving engine: continuous batching over jitted JAX steps.
 
-One :class:`InstanceEngine` is what runs on a serving instance (a TP group of
-chips).  It owns the parameters, a slotted KV cache, and pre-lowered
-executables — the TPU analogue of the paper's CUDA-context-pool trick
-(App. A.1): the decode step compiles once per (arch, n_slots) and prefill
-once per prompt-length bucket, so autoscaling never pays a compile at
-scale time.
+One :class:`InstanceEngine` is what runs on a serving instance.  It owns
+the parameters, a slotted KV cache (one contiguous ``max_seq`` cache per
+slot), and two jitted closures of its own: ``_decode_all`` (every slot, one
+token) and ``_prefill_one`` (one request).  Each engine compiles them on
+first use: the decode once, the prefill once per distinct prompt length
+(there are no length buckets).  So a newly built engine, a scaled-up one
+included, pays those compiles; the paper's CUDA-context-pool analogue
+(App. A.1), executables shared across engines, is not built.
 
 Continuous batching (Orca-style): a fixed number of decode slots; finished
 sequences free their slot immediately and queued requests are admitted at
@@ -77,9 +79,7 @@ class InstanceEngine:
         n_slots: int = 8,
         max_seq: int = 512,
     ):
-        # per-row (non-lockstep) appends: engine slots are admitted at
-        # different times, so their cache positions differ (§Perf C2 note)
-        self.cfg = cfg = cfg.replace(uniform_decode=False)
+        self.cfg = cfg
         self.params = params
         self.n_slots = n_slots
         self.max_seq = max_seq

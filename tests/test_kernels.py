@@ -34,7 +34,7 @@ def test_flash_attention_matches_oracle(b, sq, sk, h, kv, d, causal, dtype):
     k = jax.random.normal(k2, (b, sk, kv, d), dtype)
     v = jax.random.normal(k3, (b, sk, kv, d), dtype)
     got = flash_attention(q, k, v, causal=causal, block_q=32, block_k=32, interpret=True)
-    want = ref.flash_attention_ref(q, k, v, causal=causal)
+    want = ref.attention_reference(q, k, v, causal=causal)
     np.testing.assert_allclose(
         got.astype(jnp.float32), want.astype(jnp.float32), **_tol(dtype)
     )
@@ -52,7 +52,7 @@ def test_decode_attention_matches_oracle(b, s, h, kv, d, dtype):
     vc = jax.random.normal(k3, (b, kv, s, d), dtype)
     lengths = jax.random.randint(k4, (b,), 1, s + 1)
     got = decode_attention(q, kc, vc, lengths, block_s=32, interpret=True)
-    want = ref.decode_attention_ref(q, kc, vc, lengths)
+    want = ref.decode_attention_reference(q, kc, vc, lengths)
     np.testing.assert_allclose(
         got.astype(jnp.float32), want.astype(jnp.float32), **_tol(dtype)
     )
@@ -82,7 +82,7 @@ def test_rmsnorm_matches_oracle(shape, dtype):
     x = jax.random.normal(k1, shape, dtype)
     w = jax.random.normal(k2, (shape[-1],), dtype)
     got = fused_rmsnorm(x, w, block_n=16, interpret=True)
-    want = ref.rmsnorm_ref(x, w)
+    want = ref.rmsnorm(x, w)
     np.testing.assert_allclose(
         got.astype(jnp.float32), want.astype(jnp.float32), **_tol(dtype)
     )
@@ -98,7 +98,7 @@ def test_ops_dispatch_cpu_uses_ref():
     q = jax.random.normal(KEY, (b, s, h, d))
     k = jax.random.normal(KEY, (b, s, kv, d))
     v = jax.random.normal(KEY, (b, s, kv, d))
-    want = ref.flash_attention_ref(q, k, v, causal=True)
+    want = ref.attention_reference(q, k, v, causal=True)
     out = ops.flash_attention(q, k, v, causal=True, impl="ref")
     np.testing.assert_allclose(out, want, atol=1e-5, rtol=1e-5)
     out = ops.flash_attention(q, k, v, causal=True, block_q=8, block_k=8, interpret=True)

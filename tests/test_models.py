@@ -65,9 +65,49 @@ def test_prefill_then_decode_matches_longer_prefill(arch):
     np.testing.assert_array_equal(np.asarray(nxt_inc), np.asarray(nxt_full))
 
 
+@pytest.mark.parametrize("kind", ["gqa", "mla"])
+def test_append_writes_each_row_at_its_own_length(kind):
+    """Continuous batching: the decode append writes row b at lengths[b]
+    and nowhere else, leaves a row already at max_seq as it was, and
+    advances every length by one."""
+    from repro.models import kvcache
+
+    b, smax = 4, 8
+    lengths = jnp.array([0, 3, 5, smax], jnp.int32)
+    keys = jax.random.split(jax.random.PRNGKey(6), 4)
+    if kind == "gqa":
+        n_kv, d = 2, 4
+        cache = kvcache.init_kv_cache(b, smax, n_kv, d, jnp.float32)
+        leaves = ("k", "v")
+        new = [jax.random.normal(keys[i], (b, n_kv, d)) for i in (0, 1)]
+    else:
+        rank, rope = 6, 2
+        cache = kvcache.init_mla_cache(b, smax, rank, rope, jnp.float32)
+        leaves = ("ckv", "krope")
+        new = [jax.random.normal(keys[0], (b, rank)), jax.random.normal(keys[1], (b, rope))]
+    for i, name in enumerate(leaves):
+        cache[name] = jax.random.normal(keys[2 + i], cache[name].shape)
+    cache["lengths"] = lengths
+
+    append = kvcache.append_kv if kind == "gqa" else kvcache.append_mla
+    out = append(dict(cache), *new)
+
+    np.testing.assert_array_equal(np.asarray(out["lengths"]), np.asarray(lengths) + 1)
+    for name, rows in zip(leaves, new):
+        got, old = np.asarray(out[name]), np.asarray(cache[name])
+        want = old.copy()
+        for row, pos in enumerate(np.asarray(lengths)):
+            if pos < smax:
+                # GQA rows are (KV, S, D), MLA rows (S, R): seq is axis 1 or 0
+                at = (row, slice(None), pos) if kind == "gqa" else (row, pos)
+                want[at] = np.asarray(rows[row])
+        np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(got[3], old[3])  # the row at max_seq
+
+
 def test_gqa_equals_mha_when_kv_equals_heads():
     """GQA with kv=h is plain MHA: grouped attention must equal reference."""
-    from repro.models.layers import attention_reference, chunked_attention
+    from repro.kernels.ref import attention_reference, chunked_attention
 
     b, s, h, d = 2, 33, 4, 16
     q = jax.random.normal(KEY, (b, s, h, d))
